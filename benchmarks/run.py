@@ -27,6 +27,8 @@ def main() -> None:
                     help="run a single bench (mac_engine|coprocessor|"
                          "e2e|accuracy|model_size|decode|serve)")
     args = ap.parse_args()
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
     from . import (bench_accuracy, bench_coprocessor, bench_decode,
                    bench_e2e, bench_mac_engine, bench_model_size,
                    bench_serve)

@@ -20,24 +20,22 @@ from jax.experimental import pallas as pl
 from ..core import codec as codec_mod
 from ..core.formats import FormatSpec
 from ..core.packing import lanes_per_word
+from .rmmec_matmul import code_planes, interleave, planar
 
 __all__ = ["dequant_kernel", "dequant_pallas"]
 
 
 def dequant_kernel(w_ref, s_ref, o_ref, *, spec: FormatSpec,
                    group: Optional[int]):
-    per = lanes_per_word(spec.bits)
-    words = w_ref[...]
-    shifts = (jnp.arange(per, dtype=jnp.uint32) * jnp.uint32(spec.bits))
-    codes = (words[:, :, None] >> shifts) & jnp.uint32((1 << spec.bits) - 1)
-    codes = codes.reshape(words.shape[0], words.shape[1] * per)
-    w = codec_mod.decode(spec, codes, jnp.float32)
-    s = s_ref[...]
-    if group is not None:
-        bk, bn = w.shape
-        s = jnp.broadcast_to(s[:, None, :], (bk // group, group, bn)) \
-            .reshape(bk, bn)
-    o_ref[...] = w * s
+    """One (bk, bn) block, written planar as (per, bk, bn // per)."""
+    for p, codes in enumerate(code_planes(w_ref[...], spec.bits)):
+        w = codec_mod.decode(spec, codes, jnp.float32)
+        s = s_ref[p]
+        if group is not None:
+            bk, nw = w.shape
+            s = jnp.broadcast_to(s[:, None, :], (bk // group, group, nw)) \
+                .reshape(bk, nw)
+        o_ref[p] = w * s
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "bk", "bn", "group",
@@ -49,26 +47,29 @@ def dequant_pallas(w_words: jax.Array, scales: jax.Array, *,
     """(K, N/per) uint32 + (G, N) scales -> (K, N) f32.
 
     G = 1 is per-channel; G = K/group gives each K-group its own scale
-    row (``bk`` must be a multiple of ``group``).
+    row (``bk`` must be a multiple of ``group``).  On the chip ``bn //
+    per`` must be a multiple of 128 (or the whole packed width).
     """
     per = lanes_per_word(spec.bits)
     k, nw = w_words.shape
     n = nw * per
+    bw = bn // per
     assert k % bk == 0 and n % bn == 0
     if group is None:
-        s_spec = pl.BlockSpec((1, bn), lambda i, j: (0, j))
+        s_spec = pl.BlockSpec((per, 1, bw), lambda i, j: (0, 0, j))
     else:
         assert bk % group == 0 and scales.shape[0] == k // group, \
             (bk, group, scales.shape)
-        s_spec = pl.BlockSpec((bk // group, bn), lambda i, j: (i, j))
-    return pl.pallas_call(
+        s_spec = pl.BlockSpec((per, bk // group, bw), lambda i, j: (0, i, j))
+    out = pl.pallas_call(
         functools.partial(dequant_kernel, spec=spec, group=group),
         grid=(k // bk, n // bn),
         in_specs=[
-            pl.BlockSpec((bk, bn // per), lambda i, j: (i, j)),
+            pl.BlockSpec((bk, bw), lambda i, j: (i, j)),
             s_spec,
         ],
-        out_specs=pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((k, n), jnp.float32),
+        out_specs=pl.BlockSpec((per, bk, bw), lambda i, j: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((per, k, nw), jnp.float32),
         interpret=interpret,
-    )(w_words, scales)
+    )(w_words, planar(scales, per))
+    return interleave(out)

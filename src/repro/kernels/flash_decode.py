@@ -8,11 +8,22 @@ codes (+ po2 group scales) stream from HBM and are decoded per block
 VMEM-decode stage ``rmmec_matmul`` uses for weights, applied to the KV
 plane.  The bf16 cache never exists in HBM.
 
-Grid is (B, Kh, T/blk) with the KV-block axis innermost ('arbitrary'):
-the (G, Dh) output block is revisited across T steps and the online-
-softmax state (f32 accumulator, running max m, normalizer l) lives in
+Grid is (B, T/blk) with the KV-block axis innermost ('arbitrary'): one
+grid cell holds ALL Kh heads of one request, and the (Kh, G, Dh) output
+block is revisited across T steps while the online-softmax state (f32
+accumulator, running max m, normalizer l, one slab per head) lives in
 VMEM scratch, carried across grid steps exactly like the K-step
 accumulator of ``rmmec_matmul``.
+
+Block layout: the KV operands are viewed as (..., T, Kh*Dh) rows -- a
+free reshape of the (..., T, Kh, Dh) cache -- and a block is
+``(blk, Kh*Dh)``: whole token rows, every head at once.  Mosaic tiles
+the last two block dims and needs each to be (8, 128)-aligned or the
+full array dim; a per-head block ``(blk, 1, Dh)`` over (T, Kh, Dh) has a
+second-minor dim of 1, which the TPU compiler refuses, while the row
+block is full-width in its last dim and page-sized (a multiple of 8) in
+its second-minor dim.  Heads are split off by static lane slices of the
+decoded block inside the kernel, so the per-head math is unchanged.
 
 Length-aware block skipping: ``pos`` arrives as a scalar-prefetch
 operand, so the KV BlockSpec index maps clamp the T-block index to
@@ -44,7 +55,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,11 +69,9 @@ __all__ = ["flash_decode_kernel", "flash_decode_pallas", "default_kv_block",
            "paged_flash_decode_kernel", "paged_flash_decode_pallas",
            "paged_flash_prefill_kernel", "paged_flash_prefill_pallas"]
 
-# renamed across JAX versions (TPUCompilerParams -> CompilerParams)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 _NEG_INF = -1e30
+
+_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def default_kv_block(max_len: int) -> int:
@@ -75,36 +84,45 @@ def default_kv_block(max_len: int) -> int:
     return 1
 
 
-def _dequant_block(codes_ref, scale_ref, dh: int, gs: int) -> jax.Array:
-    """(1, blk, 1, Dh) uint8 codes + (1, blk, 1, Gs) scales -> (blk, Dh)
-    f32, decoded in VMEM (codec picks the branch-free path under
-    tracing).  Gs = Dh/group scale columns; Gs=1 broadcasts."""
-    codes = codes_ref[0, :, 0, :].astype(jnp.int32)
-    x = codec_mod.decode(fmt.POSIT8, codes, jnp.float32)
-    s = scale_ref[0, :, 0, :].astype(jnp.float32)
+def _rows(x: jax.Array) -> jax.Array:
+    """(..., T, Kh, X) -> (..., T, Kh*X): the row view the kernels block."""
+    return x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
+
+
+def _dequant_head(x_all, s_all, h: int, dh: int, gs: int) -> jax.Array:
+    """Head ``h`` of a decoded (blk, Kh*Dh) f32 block, times its group
+    scales from the (blk, Kh*Gs) scale block.  The scale columns are
+    expanded by exact selects (Gs = Dh/group, Gs=1 broadcasts), so every
+    element is ``decode(code) * scale`` as in the oracle."""
+    x = x_all[:, h * dh:(h + 1) * dh]
+    s = s_all[:, h * gs:(h + 1) * gs]
     if gs == 1:
         return x * s
-    return x * jnp.repeat(s, dh // gs, axis=-1)
+    grp = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) // (dh // gs)
+    full = jnp.broadcast_to(s[:, :1], x.shape)
+    for j in range(1, gs):
+        full = jnp.where(grp == j, s[:, j:j + 1], full)
+    return x * full
 
 
-def _online_softmax_step(pos_last, qpos, q2, kc_ref, ks_ref, vc_ref, vs_ref,
-                         write_out, acc_ref, m_ref, l_ref, *,
+def _online_softmax_step(pos_last, visible: Callable, q_ref, kc_ref, ks_ref,
+                         vc_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
                          blk: int, softcap: float, scale: float,
                          pad_lo=None):
     """One grid step of the online-softmax accumulation: init scratch at
     t=0, accumulate the current KV block while any query row is live for
-    it, emit the normalized output through ``write_out`` at the last
-    step.  ONE copy of the math for the contiguous-decode, paged-decode
-    and paged-prefill kernels (the bitwise-parity tests rest on it) --
-    they differ only in how the BlockSpec index maps pick the HBM block
-    and in the query geometry:
+    it, emit the normalized output at the last step.  ONE copy of the
+    math for the contiguous-decode, paged-decode and paged-prefill
+    kernels (the bitwise-parity tests rest on it) -- they differ only in
+    how the BlockSpec index maps pick the HBM block and in the query
+    geometry:
 
-      q2       : (R, Dh) row-flattened query block (decode: R = G;
-                 prefill: R = C*G, row = qi*G + gi).
-      qpos     : per-row key-visibility horizon, broadcastable against
-                 (R, blk) (decode: the scalar ``pos``; prefill:
-                 ``start + row // G`` as an (R, 1) column).
-      pos_last : scalar max of ``qpos`` -- gates dead grid steps off.
+      q_ref    : (Kh, R, Dh) query block (decode: R = G; prefill:
+                 R = C*G, row = qi*G + gi); o_ref has the same shape.
+      visible  : ``visible(kpos)`` -> bool (R, blk) key-visibility mask
+                 for the absolute key positions ``kpos`` (R, blk).
+      pos_last : scalar last live query position -- gates dead grid
+                 steps off.
       pad_lo   : optional low key-visibility bound (ragged LEFT-padded
                  batches: slots below the request's pad width are dead).
                  ``None`` (the paged kernels, where rows have no pad)
@@ -119,8 +137,8 @@ def _online_softmax_step(pos_last, qpos, q2, kc_ref, ks_ref, vc_ref, vs_ref,
     annihilating acc and l exactly -- the gated path just starts from
     the same (acc=0, m=-1e30, l=0) scratch state at that block.
     """
-    t = pl.program_id(2)
-    nt = pl.num_programs(2)
+    t = pl.program_id(1)
+    nt = pl.num_programs(1)
 
     @pl.when(t == 0)
     def _init():
@@ -136,49 +154,59 @@ def _online_softmax_step(pos_last, qpos, q2, kc_ref, ks_ref, vc_ref, vs_ref,
 
     @pl.when(live)
     def _block():
-        dh = q2.shape[-1]
-        gs = ks_ref.shape[-1]
-        q = q2.astype(jnp.float32)                        # (R, Dh)
-        k = _dequant_block(kc_ref, ks_ref, dh, gs)        # (blk, Dh)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (R, blk)
-        if softcap > 0.0:
-            s = jnp.tanh(s / softcap) * softcap
-        kpos = t * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos <= qpos, s, _NEG_INF)
+        kh, r, dh = acc_ref.shape
+        gs = ks_ref.shape[-1] // kh
+        kx = codec_mod.decode(fmt.POSIT8, kc_ref[...].astype(jnp.int32),
+                              jnp.float32)                 # (blk, Kh*Dh)
+        vx = codec_mod.decode(fmt.POSIT8, vc_ref[...].astype(jnp.int32),
+                              jnp.float32)
+        ks = ks_ref[...].astype(jnp.float32)               # (blk, Kh*Gs)
+        vs = vs_ref[...].astype(jnp.float32)
+        kpos = t * blk + jax.lax.broadcasted_iota(jnp.int32, (r, blk), 1)
+        mask = visible(kpos)
         if pad_lo is not None:
-            s = jnp.where(kpos >= pad_lo, s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        v = _dequant_block(vc_ref, vs_ref, dh, gs)        # (blk, Dh)
-        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
+            mask &= kpos >= pad_lo
+        for h in range(kh):
+            q = q_ref[h].astype(jnp.float32)                # (R, Dh)
+            k = _dequant_head(kx, ks, h, dh, gs)            # (blk, Dh)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (R, blk)
+            if softcap > 0.0:
+                s = jnp.tanh(s / softcap) * softcap
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[h] = m_new
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            v = _dequant_head(vx, vs, h, dh, gs)            # (blk, Dh)
+            pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
+            acc_ref[h] = acc_ref[h] * alpha + pv
 
     @pl.when(t == nt - 1)
     def _finalize():
-        write_out(acc_ref[...] / l_ref[...])
+        o_ref[...] = acc_ref[...] / l_ref[...]
+
+
+def _scratch(kh: int, r: int, dh: int):
+    return [pltpu.VMEM((kh, r, dh), jnp.float32),   # acc
+            pltpu.VMEM((kh, r, 1), jnp.float32),    # running max m
+            pltpu.VMEM((kh, r, 1), jnp.float32)]    # normalizer l
 
 
 def flash_decode_kernel(pos_ref, pad_ref, q_ref, kc_ref, ks_ref, vc_ref,
                         vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
                         blk: int, softcap: float, scale: float):
-    """One (B, Kh) cell; online-softmax accumulation over live KV blocks.
+    """One request; online-softmax accumulation over live KV blocks.
     ``pad_ref`` holds per-request left-pad widths ((B,), zeros for a
     non-ragged batch): slots below ``pad_ref[i]`` are masked dead, the
     left-padded twin of the causal mask."""
     pos = pos_ref[0]
-
-    def write_out(out):
-        o_ref[0, 0] = out
-
-    _online_softmax_step(pos, pos, q_ref[0, 0], kc_ref, ks_ref, vc_ref,
-                         vs_ref, write_out, acc_ref, m_ref, l_ref,
-                         blk=blk, softcap=softcap, scale=scale,
+    _online_softmax_step(pos, lambda kpos: kpos <= pos, q_ref, kc_ref,
+                         ks_ref, vc_ref, vs_ref, o_ref, acc_ref, m_ref,
+                         l_ref, blk=blk, softcap=softcap, scale=scale,
                          pad_lo=pad_ref[pl.program_id(0)])
 
 
@@ -189,13 +217,9 @@ def paged_flash_decode_kernel(pt_ref, pos_ref, q_ref, kc_ref, ks_ref,
     blocks were gathered through the page table by the index maps (the
     kernel body never sees physical page ids)."""
     pos = pos_ref[pl.program_id(0)]
-
-    def write_out(out):
-        o_ref[0, 0] = out
-
-    _online_softmax_step(pos, pos, q_ref[0, 0], kc_ref, ks_ref, vc_ref,
-                         vs_ref, write_out, acc_ref, m_ref, l_ref,
-                         blk=blk, softcap=softcap, scale=scale)
+    _online_softmax_step(pos, lambda kpos: kpos <= pos, q_ref, kc_ref,
+                         ks_ref, vc_ref, vs_ref, o_ref, acc_ref, m_ref,
+                         l_ref, blk=blk, softcap=softcap, scale=scale)
 
 
 @functools.partial(jax.jit,
@@ -240,34 +264,29 @@ def flash_decode_pallas(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
     assert t % blk == 0, (t, blk)
     nt = t // blk
 
-    def q_im(i, h, tt, pos_ref, pad_ref):
-        return (i, h, 0, 0)
+    def q_im(i, tt, pos_ref, pad_ref):
+        return (i, 0, 0, 0)
 
-    def kv_im(i, h, tt, pos_ref, pad_ref):
+    def kv_im(i, tt, pos_ref, pad_ref):
         # clamp dead blocks onto the live window: blocks past the
         # horizon re-map to the last live block and blocks fully below
         # the left pad to the first live one -- either side, the block
         # index stops changing, so Pallas re-uses the resident copy
         # (no DMA).  pad <= pos for any valid row, so lo <= hi.
-        return (i, jnp.clip(tt, pad_ref[i] // blk, pos_ref[0] // blk),
-                h, 0)
+        return (i, jnp.clip(tt, pad_ref[i] // blk, pos_ref[0] // blk), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kh, nt),
+        grid=(b, nt),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh), q_im),
-            pl.BlockSpec((1, blk, 1, dh), kv_im),
-            pl.BlockSpec((1, blk, 1, gs), kv_im),
-            pl.BlockSpec((1, blk, 1, dh), kv_im),
-            pl.BlockSpec((1, blk, 1, gs), kv_im),
+            pl.BlockSpec((None, kh, g, dh), q_im),
+            pl.BlockSpec((None, blk, kh * dh), kv_im),
+            pl.BlockSpec((None, blk, kh * gs), kv_im),
+            pl.BlockSpec((None, blk, kh * dh), kv_im),
+            pl.BlockSpec((None, blk, kh * gs), kv_im),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dh), q_im),
-        scratch_shapes=[
-            pltpu.VMEM((g, dh), jnp.float32),   # acc
-            pltpu.VMEM((g, 1), jnp.float32),    # running max m
-            pltpu.VMEM((g, 1), jnp.float32),    # normalizer l
-        ],
+        out_specs=pl.BlockSpec((None, kh, g, dh), q_im),
+        scratch_shapes=_scratch(kh, g, dh),
     )
     kernel = functools.partial(flash_decode_kernel, blk=blk,
                                softcap=float(softcap),
@@ -279,10 +298,10 @@ def flash_decode_pallas(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, dh), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(pos_arr, pad_arr, q, k_codes, k_scale, v_codes, v_scale)
+    )(pos_arr, pad_arr, q, _rows(k_codes), _rows(k_scale), _rows(v_codes),
+      _rows(v_scale))
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -317,31 +336,27 @@ def paged_flash_decode_pallas(q: jax.Array, k_codes: jax.Array,
     gs = k_scale.shape[-1]
     npp = page_table.shape[1]
 
-    def q_im(i, h, tt, pt_ref, pos_ref):
-        return (i, h, 0, 0)
+    def q_im(i, tt, pt_ref, pos_ref):
+        return (i, 0, 0, 0)
 
-    def kv_im(i, h, tt, pt_ref, pos_ref):
+    def kv_im(i, tt, pt_ref, pos_ref):
         # the PR 2 clamp, now a gather: dead steps re-read the request's
         # last live page (same block index -> no DMA)
         tc = jnp.minimum(tt, pos_ref[i] // blk)
-        return (pt_ref[i * npp + tc], 0, h, 0)
+        return (pt_ref[i * npp + tc], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kh, npp),
+        grid=(b, npp),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh), q_im),
-            pl.BlockSpec((1, blk, 1, dh), kv_im),
-            pl.BlockSpec((1, blk, 1, gs), kv_im),
-            pl.BlockSpec((1, blk, 1, dh), kv_im),
-            pl.BlockSpec((1, blk, 1, gs), kv_im),
+            pl.BlockSpec((None, kh, g, dh), q_im),
+            pl.BlockSpec((None, blk, kh * dh), kv_im),
+            pl.BlockSpec((None, blk, kh * gs), kv_im),
+            pl.BlockSpec((None, blk, kh * dh), kv_im),
+            pl.BlockSpec((None, blk, kh * gs), kv_im),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dh), q_im),
-        scratch_shapes=[
-            pltpu.VMEM((g, dh), jnp.float32),   # acc
-            pltpu.VMEM((g, 1), jnp.float32),    # running max m
-            pltpu.VMEM((g, 1), jnp.float32),    # normalizer l
-        ],
+        out_specs=pl.BlockSpec((None, kh, g, dh), q_im),
+        scratch_shapes=_scratch(kh, g, dh),
     )
     kernel = functools.partial(paged_flash_decode_kernel, blk=blk,
                                softcap=float(softcap),
@@ -352,31 +367,28 @@ def paged_flash_decode_pallas(q: jax.Array, k_codes: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, dh), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(pt_flat, pos_arr, q, k_codes, k_scale, v_codes, v_scale)
+    )(pt_flat, pos_arr, q, _rows(k_codes), _rows(k_scale), _rows(v_codes),
+      _rows(v_scale))
 
 
 def paged_flash_prefill_kernel(pt_ref, start_ref, q_ref, kc_ref, ks_ref,
                                vc_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref,
                                *, blk: int, c: int, g: int, softcap: float,
                                scale: float):
-    """One (B, Kh) cell of the paged chunk-PREFILL kernel: the SAME
+    """One request of the paged chunk-PREFILL kernel: the SAME
     online-softmax body as the decode kernels, widened to a (C*G, Dh)
-    query block (row ``qi*G + gi``); the causal horizon of row ``r`` is
-    ``start + r // G``."""
+    query block per head (row ``qi*G + gi``).  Row ``r`` sees key ``k``
+    iff ``k <= start + r // G``, evaluated division-free as
+    ``(k - start) * G <= r`` (the same set for integers, r >= 0)."""
     start = start_ref[pl.program_id(0)]
-    dh = q_ref.shape[-1]
-    q2 = q_ref[0, :, 0].reshape(c * g, dh)
-    qpos = start + jax.lax.broadcasted_iota(jnp.int32, (c * g, 1), 0) // g
-
-    def write_out(out):
-        o_ref[0, :, 0] = out.reshape(c, g, dh)
-
-    _online_softmax_step(start + c - 1, qpos, q2, kc_ref, ks_ref, vc_ref,
-                         vs_ref, write_out, acc_ref, m_ref, l_ref,
-                         blk=blk, softcap=softcap, scale=scale)
+    row = jax.lax.broadcasted_iota(jnp.int32, (c * g, blk), 0)
+    _online_softmax_step(start + c - 1,
+                         lambda kpos: (kpos - start) * g <= row,
+                         q_ref, kc_ref, ks_ref, vc_ref, vs_ref, o_ref,
+                         acc_ref, m_ref, l_ref, blk=blk, softcap=softcap,
+                         scale=scale)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -397,15 +409,16 @@ def paged_flash_prefill_pallas(q: jax.Array, k_codes: jax.Array,
     page_table       : (B, NP) int32 -- the request's previously written
                        pages plus its own (just-written) chunk pages;
                        rows padded with a parking page id.
-    start            : (B,) int32 -- query i*? attends to logical slots
-                       [0, start[i] + row] causally.
+    start            : (B,) int32 -- query row j of request i attends to
+                       logical slots [0, start[i] + j] causally.
 
     Identical page indirection to the decode kernel: the KV index map
     gathers ``page_table[i, min(t, (start[i]+C-1) // blk)]``, so grid
     steps past the chunk's last live page re-read the resident block
     (no DMA) and ``pl.when`` gates their compute off.  A chunk step
     moves ceil((start+C)/page) pages -- the chunk's causal prefix --
-    regardless of NP.
+    regardless of NP.  The queries enter head-major, (B, Kh, C*G, Dh),
+    so each head's rows are one contiguous VMEM slab.
 
     Returns (B, C, Kh, G, Dh) f32 attention output.
     """
@@ -413,41 +426,40 @@ def paged_flash_prefill_pallas(q: jax.Array, k_codes: jax.Array,
     blk = k_codes.shape[1]
     gs = k_scale.shape[-1]
     npp = page_table.shape[1]
+    r = c * g
 
-    def q_im(i, h, tt, pt_ref, start_ref):
-        return (i, 0, h, 0, 0)
+    def q_im(i, tt, pt_ref, start_ref):
+        return (i, 0, 0, 0)
 
-    def kv_im(i, h, tt, pt_ref, start_ref):
+    def kv_im(i, tt, pt_ref, start_ref):
         tc = jnp.minimum(tt, (start_ref[i] + c - 1) // blk)
-        return (pt_ref[i * npp + tc], 0, h, 0)
+        return (pt_ref[i * npp + tc], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kh, npp),
+        grid=(b, npp),
         in_specs=[
-            pl.BlockSpec((1, c, 1, g, dh), q_im),
-            pl.BlockSpec((1, blk, 1, dh), kv_im),
-            pl.BlockSpec((1, blk, 1, gs), kv_im),
-            pl.BlockSpec((1, blk, 1, dh), kv_im),
-            pl.BlockSpec((1, blk, 1, gs), kv_im),
+            pl.BlockSpec((None, kh, r, dh), q_im),
+            pl.BlockSpec((None, blk, kh * dh), kv_im),
+            pl.BlockSpec((None, blk, kh * gs), kv_im),
+            pl.BlockSpec((None, blk, kh * dh), kv_im),
+            pl.BlockSpec((None, blk, kh * gs), kv_im),
         ],
-        out_specs=pl.BlockSpec((1, c, 1, g, dh), q_im),
-        scratch_shapes=[
-            pltpu.VMEM((c * g, dh), jnp.float32),   # acc
-            pltpu.VMEM((c * g, 1), jnp.float32),    # running max m
-            pltpu.VMEM((c * g, 1), jnp.float32),    # normalizer l
-        ],
+        out_specs=pl.BlockSpec((None, kh, r, dh), q_im),
+        scratch_shapes=_scratch(kh, r, dh),
     )
     kernel = functools.partial(paged_flash_prefill_kernel, blk=blk, c=c,
                                g=g, softcap=float(softcap),
                                scale=1.0 / math.sqrt(dh))
     pt_flat = page_table.reshape(-1).astype(jnp.int32)
     start_arr = jnp.asarray(start, jnp.int32)
-    return pl.pallas_call(
+    qh = q.transpose(0, 2, 1, 3, 4).reshape(b, kh, r, dh)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, kh, g, dh), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, kh, r, dh), jnp.float32),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(pt_flat, start_arr, q, k_codes, k_scale, v_codes, v_scale)
+    )(pt_flat, start_arr, qh, _rows(k_codes), _rows(k_scale),
+      _rows(v_codes), _rows(v_scale))
+    return out.reshape(b, kh, c, g, dh).transpose(0, 2, 1, 3, 4)

@@ -247,8 +247,9 @@ def dequant(t: PackedTensor, interpret: Optional[bool] = None) -> jax.Array:
         tuple(c for c in (256, 128, 64, 32) if c % g == 0) + (g,)
     bk = 256 if (kp % 256 == 0 and (g is None or 256 % g == 0)) \
         else _first_divisor(kp, kcands)
-    bn = 512 if npad % 512 == 0 else _first_divisor(npad, (256, 128, 64, 32, 16, 8))
-    bn = max(bn, per)
+    # 128 packed words per block (the TPU lane tile); the default 2-D
+    # packing pads N to it, any other layout takes its whole width
+    bn = 128 * per if npad % (128 * per) == 0 else npad
     out = dequant_pallas(t.words, t.scales, spec=t.spec, bk=bk, bn=bn,
                          group=g, interpret=interpret)
     return out[: t.shape[0], : t.shape[1]]

@@ -30,10 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core import codec as codec_mod
 from ..core import formats as fmt
 
-# renamed across JAX versions (TPUCompilerParams -> CompilerParams)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 __all__ = ["quire_dot_kernel", "quire_dot_pallas", "QUIRE_FRAC_BITS"]
 
 QUIRE_FRAC_BITS = 22  # lsb of the lo limb = 2^-22 (posit8 product lsb)
@@ -90,7 +86,7 @@ def quire_dot_pallas(a_codes: jax.Array, b_codes: jax.Array, *,
             jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
             jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -41,24 +41,23 @@ from ..core import codec as codec_mod
 from ..core.formats import FormatSpec
 from ..core.packing import lanes_per_word
 
-__all__ = ["rmmec_matmul_kernel", "rmmec_matmul_pallas", "default_blocks"]
-
-# renamed across JAX versions (TPUCompilerParams -> CompilerParams)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+__all__ = ["rmmec_matmul_kernel", "rmmec_matmul_pallas", "default_blocks",
+           "code_planes", "planar", "interleave"]
 
 
 def default_blocks(spec: FormatSpec) -> Tuple[int, int, int]:
     """Morphable tiling: (bm, bk, bn) per precision mode.
 
     Lower-precision modes pack more codes per HBM word, so a larger K block
-    keeps the MXU fed from the same packed VMEM budget.
+    keeps the MXU fed from the same packed VMEM budget.  ``bn`` is 128
+    words wide in every mode (``bn // per == 128``): the packed-word block
+    is the lane dim the TPU compiler tiles, and it must be a multiple of
+    128.
     """
+    per = lanes_per_word(spec.bits)
     if spec.bits <= 4:
-        return (128, 1024, 256)
-    if spec.bits <= 8:
-        return (128, 512, 256)
-    return (128, 512, 128)
+        return (128, 1024, 128 * per)
+    return (128, 512, 128 * per)
 
 
 def _compute_dtype(spec: FormatSpec, x_dtype):
@@ -71,10 +70,33 @@ def _compute_dtype(spec: FormatSpec, x_dtype):
     return jnp.float32
 
 
+def code_planes(words: jax.Array, bits: int):
+    """(..., W) uint32 words -> ``per`` code planes of shape (..., W);
+    plane j holds the codes of logical columns ``w * per + j``.  Peeling
+    planes keeps every array 2-D in the word layout -- no lane interleave
+    inside the kernel; callers re-interleave outside (``planar``)."""
+    mask = jnp.uint32((1 << bits) - 1)
+    return [(words >> jnp.uint32(j * bits)) & mask
+            for j in range(lanes_per_word(bits))]
+
+
+def planar(a: jax.Array, per: int) -> jax.Array:
+    """(G, N) column-interleaved -> (per, G, N // per) planar layout."""
+    g, n = a.shape
+    return a.reshape(g, n // per, per).transpose(2, 0, 1)
+
+
+def interleave(a: jax.Array) -> jax.Array:
+    """(per, M, W) planar -> (M, W * per): the inverse of ``planar``."""
+    per, m, w = a.shape
+    return a.transpose(1, 2, 0).reshape(m, w * per)
+
+
 def rmmec_matmul_kernel(mask_ref, x_ref, w_ref, s_ref, o_ref, *,
-                        spec: FormatSpec, n_block: int, k_steps: int,
+                        spec: FormatSpec, k_steps: int,
                         group: Optional[int]):
-    """One (bm, bn) output block; K-step accumulation with block gating.
+    """One (bm, bn) output block, held planar as (per, bm, bn // per);
+    K-step accumulation with block gating.
 
     ``group`` None: per-channel scales, applied once at output (the seed
     path).  ``group`` set: the scale block holds bk/group rows and is
@@ -94,23 +116,26 @@ def rmmec_matmul_kernel(mask_ref, x_ref, w_ref, s_ref, o_ref, *,
 
     @pl.when(gate != 0)
     def _mac():
-        per = lanes_per_word(spec.bits)
-        words = w_ref[...]  # (bk, bn // per) uint32
-        shifts = (jnp.arange(per, dtype=jnp.uint32) * jnp.uint32(spec.bits))
-        codes = (words[:, :, None] >> shifts) & jnp.uint32((1 << spec.bits) - 1)
-        codes = codes.reshape(words.shape[0], words.shape[1] * per)
         cdt = _compute_dtype(spec, x_ref.dtype)
-        # RMMEC decode, in VMEM -- codec picks the branch-free path
-        w = codec_mod.decode(spec, codes, dtype=cdt)
-        if group is not None:
-            # per-group scale inside the accumulation (po2 scales are
-            # exact in bf16, so the fast path keeps its 2x MXU rate)
-            s = s_ref[...].astype(cdt)               # (bk // group, bn)
-            bk, bn = w.shape
-            w = (w.reshape(bk // group, group, bn)
-                 * s[:, None, :]).reshape(bk, bn)
+        # the MXU's default precision rounds f32 operands to bf16: an f32
+        # product (posit16's 12 fraction bits) needs the full passes
+        precision = jax.lax.Precision.HIGHEST if cdt == jnp.float32 \
+            else None
         x = x_ref[...].astype(cdt)
-        o_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+        for p, codes in enumerate(code_planes(w_ref[...], spec.bits)):
+            # RMMEC decode, in VMEM -- codec picks the branch-free path.
+            # Decode and scale in f32 (the v5e VPU has no bf16 compare),
+            # then narrow: a <=8-bit code times a po2 scale is exact in
+            # bf16, so the fast path keeps its 2x MXU rate bit for bit.
+            w = codec_mod.decode(spec, codes, jnp.float32)  # (bk, bn//per)
+            if group is not None:
+                # per-group scale inside the accumulation
+                s = s_ref[p]                                # (bk//group, .)
+                bk, nw = w.shape
+                w = (w.reshape(bk // group, group, nw)
+                     * s[:, None, :]).reshape(bk, nw)
+            o_ref[p] += jnp.dot(x, w.astype(cdt), precision=precision,
+                                preferred_element_type=jnp.float32)
 
     if group is None:
         @pl.when(k == k_steps - 1)
@@ -136,34 +161,39 @@ def rmmec_matmul_pallas(x: jax.Array, w_words: jax.Array, scales: jax.Array,
             per K-block inside the accumulation).
     mask:   (K/bk, N/bn) int32 nonzero-block map (0 -> power-gated).
     All dims must already be padded to block multiples (see ops.py).
+    The kernel works on code planes (``code_planes``): scales enter and
+    the result leaves planar, and are re-laid out here in XLA.
     """
     m, kdim = x.shape
     per = lanes_per_word(spec.bits)
     n = w_words.shape[1] * per
+    nw = bn // per
     assert m % bm == 0 and kdim % bk == 0 and n % bn == 0, (m, kdim, n)
     if group is not None:
         assert bk % group == 0 and scales.shape[0] == kdim // group, \
             (bk, group, scales.shape)
     grid = (m // bm, n // bn, kdim // bk)
     kernel = functools.partial(rmmec_matmul_kernel, spec=spec,
-                               n_block=bn, k_steps=grid[2], group=group)
+                               k_steps=grid[2], group=group)
     if group is None:
-        s_spec = pl.BlockSpec((1, bn), lambda i, j, k: (0, j))
+        s_spec = pl.BlockSpec((per, 1, nw), lambda i, j, k: (0, 0, j))
     else:
-        s_spec = pl.BlockSpec((bk // group, bn), lambda i, j, k: (k, j))
-    return pl.pallas_call(
+        s_spec = pl.BlockSpec((per, bk // group, nw),
+                              lambda i, j, k: (0, k, j))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(mask.shape, lambda i, j, k: (0, 0)),       # gate map
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),          # x
-            pl.BlockSpec((bk, bn // per), lambda i, j, k: (k, j)),   # packed w
-            s_spec,                                                  # scales
+            pl.BlockSpec(memory_space=pltpu.SMEM),               # gate map
+            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),      # x
+            pl.BlockSpec((bk, nw), lambda i, j, k: (k, j)),      # packed w
+            s_spec,                                              # scales
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_specs=pl.BlockSpec((per, bm, nw), lambda i, j, k: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((per, m, n // per), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(mask, x, w_words, scales)
+    )(mask, x, w_words, planar(scales, per))
+    return interleave(out)

@@ -52,6 +52,7 @@ from ..configs import get_config
 from ..core.policy import PrecisionPolicy
 from ..models import zoo
 from ..serve.engine import ContinuousEngine, ServeEngine
+from .cache import setup_compile_cache
 
 
 def _static(args, cfg, params, policy) -> None:
@@ -229,6 +230,7 @@ def main() -> None:
                          "engine's metric registry after the run; paged "
                          "engines only")
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

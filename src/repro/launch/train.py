@@ -17,6 +17,7 @@ from ..configs import get_config
 from ..configs.base import RunConfig
 from ..data import TokenStream
 from ..train.loop import train_loop
+from .cache import setup_compile_cache
 
 
 def main() -> None:
@@ -36,6 +37,7 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true",
                     help="use the smoke-test-sized config (CPU-friendly)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -34,8 +34,9 @@ from ..models import zoo
 from ..obs import MetricRegistry, NULL_RECORDER, bind_counters
 from .scheduler import PREFILLING, RUNNING
 
-__all__ = ["build_prefill_step", "build_prefill_chunk_step",
-           "build_serve_step", "ServeEngine", "ContinuousEngine"]
+__all__ = ["build_prefill_step", "build_token_gap_step",
+           "build_prefill_chunk_step", "build_serve_step", "ServeEngine",
+           "ContinuousEngine"]
 
 
 def build_prefill_step(cfg: ModelConfig, last_logit_only: bool = False,
@@ -69,6 +70,37 @@ def build_prefill_step(cfg: ModelConfig, last_logit_only: bool = False,
         return logits, cache
 
     return prefill
+
+
+# teacher-forced token check (``build_token_gap_step``): a served token
+# passes when its logit is within this many row standard deviations of
+# the row max.  posit8 KV and bf16 rounding move logits by a fraction
+# of the spread (up to ~0.5 of it over hundreds of positions of the
+# 2-layer smoke model), while a token from a broken path lands several
+# deviations below the max: the max of a 512-token row sits ~3, of a
+# 152k-token row ~4.5, deviations above the row mean.
+TOKEN_GAP_TOL = 1.0
+
+
+def build_token_gap_step(cfg: ModelConfig):
+    """(params, tokens (B, S)) -> (gap (B, S-1), spread (B, S-1)) f32.
+
+    Teacher forcing: one bf16-KV prefill over ``tokens`` (prompt +
+    served tokens), then at each position ``s`` how far the logit of
+    ``tokens[:, s+1]`` sits below the max of row ``s`` (0 where it is
+    the argmax), and that row's standard deviation.  A served token is
+    checked as right when its gap is within a tolerance of the spread:
+    unlike comparing greedy tokens, this does not flip on argmax ties
+    between near-equal logits."""
+    prefill = build_prefill_step(cfg)
+
+    def gap_step(params, tokens):
+        logits, _ = prefill(params, {"tokens": tokens})
+        lg = logits[:, :-1].astype(jnp.float32)
+        picked = jnp.take_along_axis(lg, tokens[:, 1:, None], axis=-1)[..., 0]
+        return jnp.max(lg, axis=-1) - picked, jnp.std(lg, axis=-1)
+
+    return gap_step
 
 
 def build_prefill_chunk_step(cfg: ModelConfig,

@@ -11,7 +11,8 @@ RunConfig) pair:
   -> global-norm clip -> warmup-cosine LR -> AdamW (8-bit opt)
 
 ``train_loop`` adds checkpoint/restart (atomic, async), preemption
-recovery (any step may raise; we restore and continue), and straggler
+recovery (any step may raise; we restore and continue -- except while
+lowering or compiling, which raises), and straggler
 mitigation hooks (deterministic data re-sharding).
 """
 
@@ -179,8 +180,16 @@ def train_loop(cfg: ModelConfig, run: RunConfig, data,
     step_fn = build_train_step(cfg, run, policy)
     history: Dict[str, list] = {"loss": [], "ce": [], "step": []}
     t0 = time.perf_counter()      # monotonic: immune to NTP clock steps
+    compiled = False
     while int(state.step) < run.steps:
         batch = data.next_batch()
+        if not compiled:
+            # lower and compile outside the retry below (the call reuses
+            # this executable): a program the compiler refuses is a
+            # fault, not a preemption, so it raises instead of looping
+            # through restores
+            step_fn.lower(state, batch).compile()
+            compiled = True
         try:
             state, metrics = step_fn(state, batch)
         except Exception:

@@ -68,11 +68,8 @@ def test_elastic_restore_new_mesh(tmp_path):
     the path exercises device_put-with-sharding, which is what a N->M
     chip restore uses)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    try:  # newer jax: explicit Auto axis types
-        mesh = jax.make_mesh((1,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):  # jax<=0.4.x has neither
-        mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     t = _tree()
     save_checkpoint(str(tmp_path), 7, t)
     sh = {"a": {"w": NamedSharding(mesh, P(None, None))},

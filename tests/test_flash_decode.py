@@ -16,7 +16,8 @@ from repro.kernels.flash_decode import default_kv_block, flash_decode_pallas
 from repro.models import attention as A
 from repro.models import transformer as T
 from repro.models import zoo
-from repro.serve.engine import ServeEngine
+from repro.serve.engine import (TOKEN_GAP_TOL, ServeEngine,
+                                build_token_gap_step)
 
 CFG = get_config("qwen2-0.5b").reduced()
 RNG = np.random.default_rng(0)
@@ -235,7 +236,6 @@ def test_engine_generate_quantized_kv():
     params = T.lm_init(jax.random.PRNGKey(0), CFG)
     toks = jnp.asarray(np.random.default_rng(2).integers(
         0, CFG.vocab, (2, 8)), jnp.int32)
-    out_f = ServeEngine(CFG, params, max_len=64).generate(toks, steps=6)
     eng_q = ServeEngine(CFG, params, max_len=64, quantized_kv=True)
     # prefill really returns codes, not a bf16 cache
     _, cache = eng_q._prefill(eng_q.params, {"tokens": toks})
@@ -245,8 +245,15 @@ def test_engine_generate_quantized_kv():
                    if hasattr(p[-1], "key"))
     out_q = eng_q.generate(toks, steps=6)
     assert out_q.shape == (2, 14) and np.isfinite(out_q).all()
-    # posit8 KV is near-lossless on this model: greedy tokens agree
-    assert (out_q == out_f).mean() > 0.9
+    # posit8 KV is near-lossless: teacher-forced through a bf16-KV
+    # prefill, every generated token's logit is within TOKEN_GAP_TOL row
+    # deviations of its row's max (ties between near-equal logits may
+    # flip the argmax, which a token-agreement check would count)
+    gap, spread = jax.jit(build_token_gap_step(CFG))(
+        eng_q.params, jnp.asarray(out_q))
+    gen = slice(toks.shape[1] - 1, out_q.shape[1] - 1)
+    rel = np.asarray(gap[:, gen] / spread[:, gen])
+    assert rel.max() <= TOKEN_GAP_TOL, rel
 
 
 def test_engine_generate_quantized_kv_grouped_policy():

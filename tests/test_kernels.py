@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st  # skips property tests w/o hypothesis
+from hypothesis import given, settings, strategies as st
 
 from repro.core import formats as F
 from repro.core import quire as Q

@@ -15,10 +15,7 @@ def test_pipeline_matches_sequential():
         import json, jax, numpy as np
         import jax.numpy as jnp
         def _mk(shape, axes):
-            try:
-                return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-            except (AttributeError, TypeError):
-                return jax.make_mesh(shape, axes)
+            return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
         mesh = _mk((4, 2), ("stage", "data"))
         from repro.parallel.pipeline import pipeline_apply
 
@@ -45,6 +42,9 @@ def test_pipeline_matches_sequential():
     """)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # the child runs on fake CPU devices, never on a chip its parent
+    # may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)

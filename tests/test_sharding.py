@@ -16,6 +16,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def _run_subprocess(code: str) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # the child runs on fake CPU devices, never on a chip its parent
+    # may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
@@ -28,10 +31,7 @@ def test_param_rules_on_mesh():
         import json, jax
         from jax.sharding import PartitionSpec as P
         def _mk(shape, axes):
-            try:
-                return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-            except (AttributeError, TypeError):
-                return jax.make_mesh(shape, axes)
+            return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
         mesh = _mk((2, 4), ("data", "model"))
         from repro.parallel.sharding import param_pspec
         out = {}
@@ -85,10 +85,7 @@ def test_train_step_compiles_sharded_and_math_matches():
 
         # sharded: 2-way data, 4-way model
         def _mk(shape, axes):
-            try:
-                return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-            except (AttributeError, TypeError):
-                return jax.make_mesh(shape, axes)
+            return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
         mesh = _mk((2, 4), ("data", "model"))
         step_fn, shard_state = build_train_step(cfg, run, mesh=mesh)
         state2 = init_state(jax.random.PRNGKey(0), cfg, run)
@@ -111,10 +108,7 @@ def test_cache_sharding_rules():
     code = textwrap.dedent("""
         import json, jax
         def _mk(shape, axes):
-            try:
-                return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-            except (AttributeError, TypeError):
-                return jax.make_mesh(shape, axes)
+            return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
         mesh = _mk((2, 4), ("data", "model"))
         from repro.parallel.sharding import cache_pspec
         out = {}

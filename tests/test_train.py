@@ -152,3 +152,37 @@ def test_train_loop_preemption_recovery(tmp_path):
         # raised outside the step; loop restarts fresh -> second call resumes
         state, _ = train_loop(CFG, run, flaky, log_every=100)
     assert int(state.step) == 16
+
+
+def test_train_loop_reraises_compile_errors(tmp_path, monkeypatch):
+    """A step that cannot be lowered is a fault, not a preemption: it
+    raises at once even with a checkpoint to restore from."""
+    import repro.train.loop as loop
+    ck = str(tmp_path / "ck")
+    run = RunConfig(arch="t", steps=2, lr=1e-3, warmup_steps=0,
+                    checkpoint_every=2, checkpoint_dir=ck)
+    train_loop(CFG, run, TokenStream(vocab=CFG.vocab, seq_len=32,
+                                     global_batch=4), log_every=100)
+
+    def broken_step(*_a, **_k):
+        def step(state, batch):
+            raise ValueError("cannot lower")
+        return jax.jit(step)
+
+    pulls = {"n": 0}
+
+    class Bounded(TokenStream):
+        # a loop that swallowed the error would restore (rewinding the
+        # stream's own state) and retry forever; stop it from outside
+        # the retry instead
+        def next_batch(self):
+            pulls["n"] += 1
+            if pulls["n"] > 3:
+                raise RuntimeError("the failed step was retried")
+            return super().next_batch()
+
+    monkeypatch.setattr(loop, "build_train_step", broken_step)
+    run2 = RunConfig(**{**run.__dict__, "steps": 6})
+    with pytest.raises(ValueError, match="cannot lower"):
+        train_loop(CFG, run2, Bounded(vocab=CFG.vocab, seq_len=32,
+                                      global_batch=4), log_every=100)
