@@ -10,8 +10,18 @@
              -> RETIRE | PREEMPT | BOUNCE
 
 - **spans** — durations of engine step phases (``capacity`` / ``admit``
-  / ``prefill`` / ``decode_dispatch`` / ``decode_sync``) and channel
-  push/pull, recorded via the ``span()`` context manager.
+  / ``prefill`` with its ``prefill_chunk`` / ``prefill_sync`` children /
+  ``decode_prepare`` / ``decode_dispatch`` / ``decode_sync`` /
+  ``decode_apply``) and channel push/pull, recorded via the ``span()``
+  context manager.
+
+Every ring entry carries an ``id`` and the ``parent`` id of the span
+open around it (None at top level), so a phase's self time is its
+duration minus what its children cover.  With ``profile`` on, each
+span also enters a ``jax.profiler.TraceAnnotation`` named by its kind
+at the same instants, which puts the engine's phases on the device
+trace's clock; this recorder is the only code in the serving plane
+that writes profiler annotations.
 
 The ring is bounded (``capacity`` entries, default 64Ki); the oldest
 entries are evicted under pressure.  Per-kind event **counts** and the
@@ -65,8 +75,12 @@ SPAN_KINDS = (
     "capacity",
     "admit",
     "prefill",
+    "prefill_chunk",
+    "prefill_sync",
+    "decode_prepare",
     "decode_dispatch",
     "decode_sync",
+    "decode_apply",
     "channel_push",
     "channel_pull",
 )
@@ -86,16 +100,25 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("rec", "kind", "rid", "args", "t0")
+    __slots__ = ("rec", "kind", "rid", "args", "t0", "id", "parent", "ann")
 
     def __init__(self, rec: "TraceRecorder", kind: str, rid, args):
         self.rec = rec
         self.kind = kind
         self.rid = rid
         self.args = args
+        self.ann = None
 
     def __enter__(self):
-        self.t0 = self.rec._now()
+        rec = self.rec
+        self.id = rec._new_id()
+        self.parent = rec._open[-1] if rec._open else None
+        rec._open.append(self.id)
+        if rec.profile:
+            from jax.profiler import TraceAnnotation
+            self.ann = TraceAnnotation(self.kind)
+            self.ann.__enter__()
+        self.t0 = rec._now()
         return self
 
     def __exit__(self, *exc):
@@ -106,8 +129,12 @@ class _Span:
 class TraceRecorder:
     """Bounded-ring recorder for lifecycle events and phase spans."""
 
-    def __init__(self, capacity: int = 65536, enabled: bool = True):
+    def __init__(self, capacity: int = 65536, enabled: bool = True,
+                 profile: bool = False):
         self.enabled = enabled
+        # also write each span into the JAX profiler's trace (when a
+        # profiler runs; a few microseconds a span when none does)
+        self.profile = profile
         self.capacity = capacity
         self._ring: deque = deque(maxlen=capacity)
         self._counts: Dict[str, int] = {}
@@ -117,12 +144,18 @@ class TraceRecorder:
         # "span/<kind>" histograms there, so the Prometheus snapshot
         # carries phase-latency percentiles.
         self.hist_registry = None
+        self._next_id = 0
+        self._open: List[int] = []   # ids of the open spans, innermost last
         self._t0 = time.perf_counter()
 
     # -- recording ---------------------------------------------------
 
     def _now(self) -> float:
         return time.perf_counter() - self._t0
+
+    def _new_id(self) -> int:
+        self._next_id += 1
+        return self._next_id
 
     def event(self, kind: str, rid: Optional[int] = None, **args) -> None:
         """Record an instant lifecycle event. No-op when disabled."""
@@ -136,7 +169,8 @@ class TraceRecorder:
         if len(self._ring) == self.capacity:
             self.dropped += 1
         self._ring.append({"ph": "i", "ts": self._now(), "kind": kind,
-                           "rid": rid, "args": args})
+                           "rid": rid, "args": args, "id": self._new_id(),
+                           "parent": self._open[-1] if self._open else None})
 
     def span(self, kind: str, rid: Optional[int] = None, **args):
         """Context manager timing a phase. No-op when disabled."""
@@ -146,11 +180,15 @@ class TraceRecorder:
 
     def _end_span(self, s: _Span) -> None:
         t1 = self._now()
+        if s.ann is not None:
+            s.ann.__exit__(None, None, None)
+        self._open.pop()
         self._counts[s.kind] = self._counts.get(s.kind, 0) + 1
         if len(self._ring) == self.capacity:
             self.dropped += 1
         self._ring.append({"ph": "X", "ts": s.t0, "dur": t1 - s.t0,
-                           "kind": s.kind, "rid": s.rid, "args": s.args})
+                           "kind": s.kind, "rid": s.rid, "args": s.args,
+                           "id": s.id, "parent": s.parent})
         if self.hist_registry is not None:
             self.hist_registry.histogram(f"span/{s.kind}").observe(t1 - s.t0)
 
@@ -250,6 +288,7 @@ class TraceRecorder:
         One process; tid 0 is the engine step lane, tid ``rid + 1`` is
         the per-request lane.  Spans are ``ph="X"`` complete events,
         lifecycle events are ``ph="i"`` instants; timestamps in µs.
+        Each event's args carry its ``id`` and its ``parent`` span's id.
         """
         evs: List[dict] = [
             {"ph": "M", "pid": 0, "tid": 0, "name": "process_name",
@@ -265,7 +304,7 @@ class TraceRecorder:
         for e in self._ring:
             rid = e["rid"]
             tid = 0 if rid is None else int(rid) + 1
-            args = dict(e["args"])
+            args = dict(e["args"], id=e["id"], parent=e["parent"])
             if rid is not None:
                 args["rid"] = int(rid)
             out = {"name": e["kind"], "pid": 0, "tid": tid,

@@ -71,7 +71,6 @@ three leg pairs.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -88,14 +87,12 @@ from ..obs import MetricRegistry, NULL_RECORDER, bind_counters
 from .engine import (_build_decode_loop, _ChunkPrefillMixin,
                      _apply_decode_tokens, _decode_horizon,
                      _device_only, _dispatch_decode_loop, _PageTableCache,
-                     _trace_counted, build_prefill_chunk_step)
+                     _recorder, _trace_counted, build_prefill_chunk_step)
 from .paged_kv import PagedKVPool
 from .scheduler import RUNNING, DecodeRunner, Request, Scheduler
 
 __all__ = ["PageHandoffChannel", "PrefillWorker", "DecodeWorker",
            "DisaggEngine"]
-
-_NULL_CTX = contextlib.nullcontext()
 
 
 class PageHandoffChannel:
@@ -316,8 +313,7 @@ class DecodeWorker:
                  page_size: int, max_batch: int, max_pages_per_req: int,
                  kv_group: Optional[int], temperature: float, base_key,
                  decode_steps: int, device=None,
-                 registry: Optional[MetricRegistry] = None, trace=None,
-                 annotation=None):
+                 registry: Optional[MetricRegistry] = None, trace=None):
         self.params = params
         self.max_batch = max_batch
         self.max_pages_per_req = max_pages_per_req
@@ -325,7 +321,6 @@ class DecodeWorker:
         self._base_key = base_key
         self.metrics = registry if registry is not None else MetricRegistry()
         self._trace = trace if trace is not None else NULL_RECORDER
-        self._annotation = annotation
         n_slabs = max_batch \
             if "state" in PagedKVPool.page_kinds(cfg) else 0
         pool = PagedKVPool(cfg, n_pages, page_size, kv_group,
@@ -399,25 +394,25 @@ class DecodeWorker:
         on pool exhaustion).  Returns the in-flight dispatch record, or
         None if nothing decoded."""
         runner = self.runner
-        running = []
-        for req in list(runner.running):
-            if req.status == RUNNING and runner.ensure_capacity(
-                    req, horizon=_decode_horizon(req, self.decode_steps)):
-                running.append(req)
-        self.last_positions = [req.position for req in running]
+        tr = self._trace
+        with tr.span("decode_prepare"):
+            running = []
+            for req in list(runner.running):
+                if req.status == RUNNING and runner.ensure_capacity(
+                        req, horizon=_decode_horizon(req, self.decode_steps)):
+                    running.append(req)
+            self.last_positions = [req.position for req in running]
         if not running:
             return None
-        ann = self._annotation("decode_dispatch") \
-            if self._annotation is not None else _NULL_CTX
-        with ann, _device_only(self.transfer_guard):
+        with tr.span("decode_dispatch"), _device_only(self.transfer_guard):
             disp = _dispatch_decode_loop(
                 self._decode_loop, self.params, self.pool, running,
                 self.max_batch, self._pt_cache, runner.epoch,
                 self.max_pages_per_req, self._base_key)
         self.decode_dispatches += 1
         self.page_table_uploads += disp["uploaded"]
-        self._trace.event("DECODE_DISPATCH", batch=len(running),
-                          k=self.decode_steps, uploaded=disp["uploaded"])
+        tr.event("DECODE_DISPATCH", batch=len(running), k=self.decode_steps,
+                 uploaded=disp["uploaded"], positions=self.last_positions)
         return disp
 
     def sync(self, disp) -> int:
@@ -426,12 +421,15 @@ class DecodeWorker:
         Returns decoded request count."""
         if disp is None:
             return 0
-        with _device_only(self.transfer_guard):
+        tr = self._trace
+        with tr.span("decode_sync"), _device_only(self.transfer_guard):
             # the ONE sanctioned (B, K) host sync of the decode side
             toks = jax.device_get(disp["toks_dev"])
         self.token_host_bytes += toks.nbytes
-        self._trace.event("DECODE_SYNC", token_bytes=toks.nbytes)
-        return _apply_decode_tokens(disp, toks, self.runner.retire)
+        with tr.span("decode_apply"):
+            appended = _apply_decode_tokens(disp, toks, self.runner.retire)
+            tr.event("DECODE_SYNC", token_bytes=toks.nbytes, tokens=appended)
+        return len(appended)
 
 
 @dataclasses.dataclass
@@ -520,14 +518,9 @@ class DisaggEngine:
         base_key = jax.random.PRNGKey(self.seed)
         # one registry + recorder spans the engine and both workers
         self.metrics = MetricRegistry()
-        self._trace = self.trace if self.trace is not None else NULL_RECORDER
-        if self._trace.enabled and self._trace.hist_registry is None:
-            self._trace.hist_registry = self.metrics
+        self._trace = _recorder(self.trace, self.profile_annotations,
+                                self.metrics)
         bind_counters(self, self.metrics, "engine")
-        annotation = None
-        if self.profile_annotations:
-            from jax.profiler import TraceAnnotation
-            annotation = TraceAnnotation
         params_p = self.params if self.prefill_device is None else \
             jax.device_put(self.params, self.prefill_device)
         params_d = self.params if self.decode_device is None else \
@@ -544,8 +537,7 @@ class DisaggEngine:
             self.max_batch, self.max_pages_per_req, kv_group,
             self.temperature, base_key, self.decode_steps,
             device=self.decode_device,
-            registry=self.metrics, trace=self._trace,
-            annotation=annotation)
+            registry=self.metrics, trace=self._trace)
         self.channel = PageHandoffChannel(self.channel_depth,
                                           device=self.decode_device,
                                           registry=self.metrics,
@@ -598,16 +590,14 @@ class DisaggEngine:
             with tr.span("admit"):
                 self.decode.admit_handoffs(self.channel)
             t0 = time.perf_counter()
-            with tr.span("decode_dispatch"):
-                disp = self.decode.dispatch()
+            disp = self.decode.dispatch()  # decode_prepare, decode_dispatch
             t1 = time.perf_counter()
             for req in self.decode.runner.drain_bounced():
                 self.prefill.scheduler.reaccept(req)
             with tr.span("prefill"):
                 self.prefill.step(self.channel)
             t2 = time.perf_counter()
-            with tr.span("decode_sync"):
-                n = self.decode.sync(disp)
+            n = self.decode.sync(disp)     # decode_sync, decode_apply
             t3 = time.perf_counter()
             self.last_decode_step_s = (t1 - t0) + (t3 - t2)
             self._step_hist.observe(self.last_decode_step_s * 1e3)

@@ -31,12 +31,13 @@ from ..configs.base import ModelConfig
 from ..core.policy import PrecisionPolicy
 from ..models import ssm
 from ..models import zoo
-from ..obs import MetricRegistry, NULL_RECORDER, bind_counters
+from ..obs import MetricRegistry, NULL_RECORDER, TraceRecorder, bind_counters
+from .programs import PROGRAM_NAMES, program
 from .scheduler import PREFILLING, RUNNING
 
 __all__ = ["build_prefill_step", "build_token_gap_step",
            "build_prefill_chunk_step", "build_serve_step", "ServeEngine",
-           "ContinuousEngine"]
+           "ContinuousEngine", "PROGRAM_NAMES"]
 
 
 def build_prefill_step(cfg: ModelConfig, last_logit_only: bool = False,
@@ -150,7 +151,7 @@ def build_prefill_chunk_step(cfg: ModelConfig,
             return logits, new_cache
         return logits, new_cache, zoo.quantize_cache(new_cache, kv_group)
 
-    return chunk_step
+    return program(chunk_step, "chunk_step")
 
 
 def build_serve_step(cfg: ModelConfig, ragged: bool = False,
@@ -346,6 +347,20 @@ def _trace_counted(fn, counts: Dict[str, int], name: str):
     return counted
 
 
+def _recorder(trace, profile_annotations: bool, registry):
+    """The engine's span recorder: ``trace`` if given, else the shared
+    no-op recorder (a fresh one under ``profile_annotations``, which
+    also turns its ``profile`` switch on).  An enabled recorder
+    observes span durations into ``registry``."""
+    if trace is None:
+        trace = TraceRecorder() if profile_annotations else NULL_RECORDER
+    if profile_annotations:
+        trace.profile = True
+    if trace.enabled and trace.hist_registry is None:
+        trace.hist_registry = registry
+    return trace
+
+
 def _device_only(on: bool):
     """A FRESH ``jax.transfer_guard("disallow")`` context when ``on``
     (jax guard contexts are single-use, so each guarded window needs
@@ -358,14 +373,17 @@ def _device_only(on: bool):
     return jax.transfer_guard("disallow") if on else contextlib.nullcontext()
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _ctx_write(buf: jax.Array, chunk: jax.Array, start) -> jax.Array:
+def _carry_write(buf: jax.Array, chunk: jax.Array, start) -> jax.Array:
     """dynamic_update_slice one bf16 KV chunk (L, 1, C, Kh, Dh) into the
     preallocated prefill carry at seq offset ``start``.  The carry is
     donated, so XLA updates the resident buffer instead of copying the
     whole prefix per chunk (the old per-chunk concatenate was O(T^2)
     bytes over a T-token prefill)."""
     return jax.lax.dynamic_update_slice(buf, chunk, (0, 0, start, 0, 0))
+
+
+_ctx_write = jax.jit(program(_carry_write, "carry_write"),
+                     donate_argnums=(0,))
 
 
 def _build_decode_loop(cfg: ModelConfig, temperature: float, k_steps: int):
@@ -471,7 +489,7 @@ def _build_decode_loop(cfg: ModelConfig, temperature: float, k_steps: int):
             body, carry0, None, length=k_steps)
         return toks.T, cache                                 # (B, K)
 
-    return loop
+    return program(loop, "decode_loop")
 
 
 def _decode_horizon(req, decode_steps: int) -> int:
@@ -559,22 +577,26 @@ def _dispatch_decode_loop(loop, params, pool, running, b: int,
             "uploaded": int(uploaded)}
 
 
-def _apply_decode_tokens(disp, toks: np.ndarray, retire) -> int:
+def _apply_decode_tokens(disp, toks: np.ndarray, retire) -> List[int]:
     """Replay the device done-logic of a dispatch on host: walk each
     row's (K,) tokens until its budget or EOS froze it (later slots are
     frozen copies the scan never wrote anywhere live), retiring done
-    requests through ``retire``.  Returns the decoded request count."""
+    requests through ``retire``.  Returns the tokens appended to each
+    decoded request, in dispatch row order."""
     k_steps = toks.shape[1]
+    appended = []
     for row, req in enumerate(disp["running"]):
+        before = len(req.generated)
         for j in range(min(k_steps, int(disp["budget"][row]))):
             nxt = int(toks[row, j])
             req.generated.append(nxt)
             req.next_token = nxt
             if req.done:
                 break
+        appended.append(len(req.generated) - before)
         if req.done:
             retire(req)
-    return len(disp["running"])
+    return appended
 
 
 class _ChunkPrefillMixin:
@@ -647,38 +669,14 @@ class _ChunkPrefillMixin:
         return int(jax.random.categorical(
             sub, jnp.asarray(lg, jnp.float32) / self.temperature))
 
-    def _prefill_chunk(self, req) -> int:
-        """Run at most ONE prefill chunk for ``req``: allocate the pages
-        the chunk's slots land in (lazy, can preempt younger requests),
-        forward the chunk against the request's prefilled context, and
-        scatter its quantized KV into pages.  Completes prefill (samples
-        the first token, PREFILLING -> RUNNING) when the chunk covers
-        the prefix's last real token.  Returns the prefill tokens spent
-        (the padded chunk width; 0 if ``req`` was preempted before any
-        compute)."""
-        sched = self.scheduler
+    def _forward_chunk(self, req, start: int, c: int, real: int):
+        """Upload one chunk's tokens, run the chunk step against the
+        request's prefilled context, scatter the chunk's quantized KV
+        into its pages and grow (or, on completion, retire) the carry.
+        Returns the chunk's logits, still on the device."""
         prefix = req.prefix
         ln = prefix.size
-        # the cursor starts past the matched shared pages of a prefix-
-        # cache hit (page-aligned by construction), so a hit computes
-        # only its un-cached remainder
-        start = req.prefilled
         stateful = self.pool.has_state
-        if stateful:
-            # stateful chunks are UNPADDED: every forwarded token runs
-            # through the recurrent state, so pad tokens would corrupt
-            # it (the KV scatter pads the trailing partial page block
-            # inside write_chunk instead)
-            c = ln - start if self.prefill_chunk_tokens is None \
-                else min(self.prefill_chunk_tokens, ln - start)
-        elif self.prefill_chunk_tokens is None:
-            # monolithic: one chunk covering every remaining page slot
-            c = self.pool.pages_for(ln) * self.page_size - start
-        else:
-            c = self.prefill_chunk_tokens
-        real = min(c, ln - start)
-        if not sched.ensure_prefill_capacity(req, start + real):
-            return 0                     # self-preempted: pool too dry
         toks = np.zeros((1, c), np.int32)
         toks[0, :real] = prefix[start:start + real]
         start_arr = jnp.full((1,), start, jnp.int32)
@@ -693,35 +691,72 @@ class _ChunkPrefillMixin:
             self.pool.set_device_state(
                 {key: new_cache[key] for key in
                  ("k_codes", "v_codes", "k_scale", "v_scale")})
+            return logits
+        ctx = self._prefill_ctx.get(req.rid)
+        if start == 0 or ctx is None:
+            ctx = self._empty_ctx()
+        logits, kv, chunk_q = self._chunk_step(
+            self.params, jnp.asarray(toks), ctx, start_arr)
+        if self.pool.has_kv:
+            self.pool.write_chunk(
+                chunk_q[self._attn_key] if stateful else chunk_q,
+                req.pages, start)
+        if start + real < ln:            # full chunk: extend the carry
+            self._prefill_ctx[req.rid] = self._grow_ctx(ctx, kv, start, ln)
+        elif stateful:
+            # prefill completion writes the carried state into the
+            # request's slab ONCE, quantized exactly like the static
+            # oracle's post-prefill quantize_cache
+            state_part = kv if not self.pool.has_kv else \
+                {k: v for k, v in kv.items() if k != self._attn_key}
+            self.pool.write_state(
+                ssm.quantize_state(state_part, self.pool.kv_group), req.slab)
+        return logits
+
+    def _prefill_chunk(self, req) -> int:
+        """Run at most ONE prefill chunk for ``req``: allocate the pages
+        the chunk's slots land in (lazy, can preempt younger requests),
+        forward the chunk against the request's prefilled context, and
+        scatter its quantized KV into pages (the ``prefill_chunk``
+        span).  Completes prefill (reads the first token's logits back,
+        the ``prefill_sync`` span, samples it, PREFILLING -> RUNNING)
+        when the chunk covers the prefix's last real token.  Returns the
+        prefill tokens spent (the padded chunk width; 0 if ``req`` was
+        preempted before any compute)."""
+        sched = self.scheduler
+        ln = req.prefix.size
+        # the cursor starts past the matched shared pages of a prefix-
+        # cache hit (page-aligned by construction), so a hit computes
+        # only its un-cached remainder
+        start = req.prefilled
+        if self.pool.has_state:
+            # stateful chunks are UNPADDED: every forwarded token runs
+            # through the recurrent state, so pad tokens would corrupt
+            # it (the KV scatter pads the trailing partial page block
+            # inside write_chunk instead)
+            c = ln - start if self.prefill_chunk_tokens is None \
+                else min(self.prefill_chunk_tokens, ln - start)
+        elif self.prefill_chunk_tokens is None:
+            # monolithic: one chunk covering every remaining page slot
+            c = self.pool.pages_for(ln) * self.page_size - start
         else:
-            ctx = self._prefill_ctx.get(req.rid)
-            if start == 0 or ctx is None:
-                ctx = self._empty_ctx()
-            logits, kv, chunk_q = self._chunk_step(
-                self.params, jnp.asarray(toks), ctx, start_arr)
-            if self.pool.has_kv:
-                self.pool.write_chunk(
-                    chunk_q[self._attn_key] if stateful else chunk_q,
-                    req.pages, start)
-            if start + real < ln:        # full chunk: extend the carry
-                self._prefill_ctx[req.rid] = self._grow_ctx(
-                    ctx, kv, start, ln)
-            elif stateful:
-                # prefill completion writes the carried state into the
-                # request's slab ONCE, quantized exactly like the
-                # static oracle's post-prefill quantize_cache
-                state_part = kv if not self.pool.has_kv else \
-                    {k: v for k, v in kv.items() if k != self._attn_key}
-                self.pool.write_state(
-                    ssm.quantize_state(state_part, self.pool.kv_group),
-                    req.slab)
-        req.prefilled = start + real
-        self.prefill_tokens_computed += real
-        self._trace.event("PREFILL_CHUNK", rid=req.rid, start=start,
-                          width=c, real=real)
+            c = self.prefill_chunk_tokens
+        real = min(c, ln - start)
+        tr = self._trace
+        with tr.span("prefill_chunk", rid=req.rid, start=start, real=real):
+            if not sched.ensure_prefill_capacity(req, start + real):
+                return 0                 # self-preempted: pool too dry
+            logits = self._forward_chunk(req, start, c, real)
+            req.prefilled = start + real
+            self.prefill_tokens_computed += real
+            tr.event("PREFILL_CHUNK", rid=req.rid, start=start, width=c,
+                     real=real)
         if req.prefilled == ln:
             self._prefill_ctx.pop(req.rid, None)
-            nxt = self._sample(jax.device_get(logits[0, real - 1]), req)
+            with tr.span("prefill_sync", rid=req.rid):
+                # the first token's logit row: the prefill's device sync
+                lg = jax.device_get(logits[0, real - 1])
+            nxt = self._sample(lg, req)
             req.generated.append(nxt)
             req.next_token = nxt
             sched.prefill_complete(req)
@@ -847,9 +882,10 @@ class ContinuousEngine(_ChunkPrefillMixin):
     # capturing lifecycle events + step spans, or None for the shared
     # no-op recorder -- telemetry is host-side bookkeeping only, so
     # temperature-0 outputs are bitwise identical with tracing on or
-    # off.  ``profile_annotations`` additionally wraps each decode
-    # dispatch in ``jax.profiler.TraceAnnotation`` so device profiles
-    # carry the engine's phase names.
+    # off.  ``profile_annotations`` turns on the recorder's ``profile``
+    # switch (making a recorder if none was given): every span is also
+    # a JAX profiler annotation, so device profiles carry the engine's
+    # phase names on their own clock.
     trace: Any = None
     profile_annotations: bool = False
     # runtime transfer guard (bench/test harness hook): when True, the
@@ -934,14 +970,9 @@ class ContinuousEngine(_ChunkPrefillMixin):
         # one registry spans every layer of this engine; the recorder
         # defaults to the shared no-op (one predicted branch per call)
         self.metrics = MetricRegistry()
-        self._trace = self.trace if self.trace is not None else NULL_RECORDER
-        if self._trace.enabled and self._trace.hist_registry is None:
-            self._trace.hist_registry = self.metrics
+        self._trace = _recorder(self.trace, self.profile_annotations,
+                                self.metrics)
         bind_counters(self, self.metrics, "engine")
-        self._annotation = None
-        if self.profile_annotations:
-            from jax.profiler import TraceAnnotation
-            self._annotation = TraceAnnotation
         n_slabs = 0
         if "state" in kinds:
             n_slabs = self.n_state_slabs \
@@ -1072,17 +1103,17 @@ class ContinuousEngine(_ChunkPrefillMixin):
             # their decode window writes -- their admission gate already
             # reserved budget for the first write, so this never
             # preempts a same-step admission)
-            running = []
-            for req in list(sched.running):
-                if req.status == RUNNING and sched.ensure_capacity(
-                        req, horizon=_decode_horizon(req, self.decode_steps)):
-                    running.append(req)
-            self.last_positions = [req.position for req in running]
+            with tr.span("decode_prepare"):
+                running = []
+                for req in list(sched.running):
+                    if req.status == RUNNING and sched.ensure_capacity(
+                            req, horizon=_decode_horizon(
+                                req, self.decode_steps)):
+                        running.append(req)
+                self.last_positions = [req.position for req in running]
             if not running:
                 return 0
-            ann = self._annotation("decode_dispatch") \
-                if self._annotation is not None else contextlib.nullcontext()
-            with tr.span("decode_dispatch"), ann, \
+            with tr.span("decode_dispatch"), \
                     _device_only(self.transfer_guard):
                 disp = _dispatch_decode_loop(
                     self._decode_loop, self.params, self.pool, running,
@@ -1091,15 +1122,18 @@ class ContinuousEngine(_ChunkPrefillMixin):
             self.decode_dispatches += 1
             self.page_table_uploads += disp["uploaded"]
             tr.event("DECODE_DISPATCH", batch=len(running),
-                     k=self.decode_steps, uploaded=disp["uploaded"])
+                     k=self.decode_steps, uploaded=disp["uploaded"],
+                     positions=self.last_positions)
             with tr.span("decode_sync"), _device_only(self.transfer_guard):
                 # the ONE sanctioned (B, K) host sync of the step
                 toks = jax.device_get(disp["toks_dev"])
             self.token_host_bytes += toks.nbytes
-            tr.event("DECODE_SYNC", token_bytes=toks.nbytes)
-            n = _apply_decode_tokens(disp, toks, sched.retire)
+            with tr.span("decode_apply"):
+                appended = _apply_decode_tokens(disp, toks, sched.retire)
+                tr.event("DECODE_SYNC", token_bytes=toks.nbytes,
+                         tokens=appended)
             self.steps_run += 1
-            return n
+            return len(appended)
 
     # -- counters -----------------------------------------------------------
 

@@ -96,7 +96,6 @@ hand off bitwise through ``export_state``/``import_state``.
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -107,6 +106,7 @@ from ..configs.base import ModelConfig
 from ..models import ssm as _ssm
 from ..models import transformer as _transformer
 from ..models.attention import kv_scale_cols
+from .programs import program
 
 __all__ = ["PARKING_PAGE", "PARKING_SLAB", "PagedKVPool",
            "paged_kv_bytes_per_step", "page_handoff_bytes",
@@ -127,13 +127,16 @@ PARKING_PAGE = 0
 PARKING_SLAB = 0
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_pages(dst: jax.Array, src: jax.Array,
-                   idx: jax.Array) -> jax.Array:
+def _page_scatter(dst: jax.Array, src: jax.Array,
+                  idx: jax.Array) -> jax.Array:
     """In-place page scatter: ``dst`` is donated, so XLA updates the pool
     buffer where it lives instead of copying the whole L x P x page
     array per admission."""
     return dst.at[:, idx].set(src)
+
+
+_scatter_pages = jax.jit(program(_page_scatter, "page_scatter"),
+                         donate_argnums=(0,))
 
 
 def _init_state_buffers(cfg: ModelConfig, n_slabs: int,

@@ -26,6 +26,7 @@ from repro.obs import (
     Histogram,
     MetricRegistry,
     NULL_RECORDER,
+    SPAN_KINDS,
     TraceRecorder,
     bind_counters,
     pctl_ms,
@@ -298,3 +299,210 @@ def test_traced_engine_parity_and_tieouts(tmp_path):
     with open(path) as f:
         stats = validate_chrome_trace(json.load(f))
     assert stats["spans"] > 0 and stats["instants"] > 0
+
+
+# ---------------------------------------------------------------------------
+# span ids, parents and profiler annotations
+# ---------------------------------------------------------------------------
+
+def _nested_spans(rec):
+    with rec.span("step"):
+        with rec.span("prefill"):
+            for start in (0, 16):
+                with rec.span("prefill_chunk", rid=3, start=start, real=16):
+                    rec.event("PREFILL_CHUNK", rid=3, start=start)
+            with rec.span("prefill_sync", rid=3):
+                pass
+        with rec.span("decode_dispatch"):
+            pass
+    rec.event("SUBMIT", rid=4)
+
+
+def test_span_ids_and_parents():
+    rec = TraceRecorder()
+    _nested_spans(rec)
+    by_id = {e["id"]: e for e in rec.events()}
+    assert len(by_id) == len(rec)               # ids are unique
+    kind_of = {i: e["kind"] for i, e in by_id.items()}
+    links = sorted((e["kind"], kind_of.get(e["parent"]))
+                   for e in rec.events())
+    assert links == sorted([
+        ("step", None), ("prefill", "step"),
+        ("prefill_chunk", "prefill"), ("prefill_chunk", "prefill"),
+        ("PREFILL_CHUNK", "prefill_chunk"), ("PREFILL_CHUNK", "prefill_chunk"),
+        ("prefill_sync", "prefill"), ("decode_dispatch", "step"),
+        ("SUBMIT", None)])
+    # a child lies inside its parent
+    for e in rec.events(kind="prefill_chunk"):
+        p = by_id[e["parent"]]
+        assert p["ts"] <= e["ts"] and \
+            e["ts"] + e["dur"] <= p["ts"] + p["dur"]
+    # the Chrome export carries both links in its args
+    ct = [e for e in rec.chrome_trace()["traceEvents"] if e["ph"] != "M"]
+    assert sorted((e["args"]["id"], e["args"]["parent"]) for e in ct) == \
+        sorted((e["id"], e["parent"]) for e in rec.events())
+    assert set(SPAN_KINDS) >= {"prefill_chunk", "prefill_sync",
+                               "decode_prepare", "decode_apply"}
+
+
+def _host_annotations(trace_dir, names):
+    """(name, start_ns, end_ns) of the host events named in ``names``
+    in the profiler trace under ``trace_dir``."""
+    import glob
+    from jax.profiler import ProfileData
+    path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in names:
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns))
+    return out
+
+
+@pytest.mark.parametrize("profile", [True, False])
+def test_profile_switch_mirrors_spans_as_annotations(tmp_path, profile):
+    """With ``profile`` on, every span is a profiler annotation of the
+    same name, with the same count and nesting as the ring; off, the
+    trace holds none of them."""
+    rec = TraceRecorder(profile=profile)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _nested_spans(rec)
+    finally:
+        jax.profiler.stop_trace()
+    spans = [e for e in rec.events() if e["ph"] == "X"]
+    anns = _host_annotations(tmp_path, set(SPAN_KINDS))
+    if not profile:
+        assert anns == []
+        return
+    assert sorted(n for n, _, _ in anns) == sorted(e["kind"] for e in spans)
+
+    def parent(a):
+        outer = [b for b in anns if b is not a and b[1] <= a[1]
+                 and a[2] <= b[2]]
+        return min(outer, key=lambda b: b[2] - b[1])[0] if outer else None
+
+    kind_of = {e["id"]: e["kind"] for e in spans}
+    assert sorted((a[0], parent(a)) for a in anns) == sorted(
+        (e["kind"], kind_of.get(e["parent"])) for e in spans)
+
+
+def test_disabled_recorder_with_profile_records_nothing(tmp_path):
+    rec = TraceRecorder(enabled=False, profile=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _nested_spans(rec)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(rec) == 0 and rec._counts == {}
+    assert _host_annotations(tmp_path, set(SPAN_KINDS)) == []
+
+
+def test_engine_step_children_cover_the_step():
+    """Each phase of a step has a span of its own, so a step's self time
+    (what its child spans leave uncovered) is a few percent at most."""
+    rec = TraceRecorder()
+    eng, _, _ = _drive(trace=rec)
+    spans = [e for e in rec.events() if e["ph"] == "X"]
+    steps = {e["id"]: e["dur"] for e in spans if e["kind"] == "step"}
+    children = {}
+    for e in spans:
+        if e["parent"] in steps:
+            children[e["parent"]] = children.get(e["parent"], 0.0) + e["dur"]
+    kinds = {e["kind"] for e in spans if e["parent"] in steps}
+    assert kinds == {"capacity", "admit", "prefill", "decode_prepare",
+                     "decode_dispatch", "decode_sync", "decode_apply"}
+    assert sum(children.values()) >= 0.95 * sum(steps.values())
+    # prefill's children: one span per chunk and one per first token
+    chunks = rec.events(kind="prefill_chunk")
+    assert len(chunks) == rec.count("PREFILL_CHUNK")
+    assert len(rec.events(kind="prefill_sync")) == \
+        rec.count("PREFILL_COMPLETE")
+    assert all({"start", "real"} <= set(e["args"]) and e["rid"] is not None
+               for e in chunks)
+
+
+def test_decode_events_carry_positions_and_tokens():
+    rec = TraceRecorder()
+    eng, rids, outs = _drive(trace=rec)
+    disp = rec.events(kind="DECODE_DISPATCH")
+    sync = rec.events(kind="DECODE_SYNC")
+    assert len(disp) == len(sync) == eng.decode_dispatches
+    for d, s in zip(disp, sync):
+        assert len(d["args"]["positions"]) == d["args"]["batch"] == \
+            len(s["args"]["tokens"])
+        assert all(1 <= n <= eng.decode_steps for n in s["args"]["tokens"])
+    # every generated token but the first (sampled at prefill) comes
+    # from a decode dispatch
+    generated = sum(len(o) for o in outs) - sum(
+        rec.events(kind="SUBMIT", rid=r)[0]["args"]["prompt_tokens"]
+        for r in rids)
+    assert sum(sum(s["args"]["tokens"]) for s in sync) == \
+        generated - len(rids)
+
+
+def test_profile_annotations_switch_the_engine_recorder_on():
+    from repro.serve import DisaggEngine
+    rec = TraceRecorder()
+    kw = dict(max_batch=4, max_len=48, page_size=16)
+    ContinuousEngine(CFG, PARAMS, trace=rec, profile_annotations=True, **kw)
+    assert rec.profile
+    own = ContinuousEngine(CFG, PARAMS, profile_annotations=True, **kw)
+    assert own._trace.enabled and own._trace.profile
+    assert not NULL_RECORDER.profile
+    plain = ContinuousEngine(CFG, PARAMS, **kw)
+    assert plain._trace is NULL_RECORDER
+    d = DisaggEngine(CFG, PARAMS, profile_annotations=True, **kw)
+    assert d._trace.profile and d.decode._trace is d._trace
+
+
+def test_served_programs_lower_under_their_table_names():
+    """Each served program lowers as ``jit_<name>`` with the name the
+    table gives its role: the device trace names its runs so."""
+    from repro.models import zoo
+    from repro.serve.engine import PROGRAM_NAMES, _ctx_write
+    from repro.serve.paged_kv import _scatter_pages
+    eng = ContinuousEngine(CFG, PARAMS, n_pages=8, page_size=16,
+                           max_batch=2, max_len=48,
+                           prefill_chunk_tokens=16, decode_steps=2)
+    b, i32 = eng.max_batch, np.int32
+    vec = np.zeros((b,), i32)
+    loop = eng._decode_loop.lower(
+        eng.params, np.zeros((b, 1), i32), vec, eng.pool.device_state(),
+        np.zeros((b, eng.max_pages_per_req), i32), vec,
+        np.zeros((b,), bool), vec, vec, vec, vec, eng._base_key)
+    chunk = eng._chunk_step.lower(eng.params, np.zeros((1, 16), i32),
+                                  eng._empty_ctx(), np.zeros((1,), i32))
+    carry = zoo.init_cache(CFG, 1, 32)["k"]
+    write = _ctx_write.lower(carry, carry[:, :, :16], np.int32(0))
+    codes = eng.pool.device_state()["k_codes"]
+    scatter = _scatter_pages.lower(codes, codes[:, :1], np.zeros((1,), i32))
+
+    def module(lowered):
+        return lowered.as_text().split("module @", 1)[1].split()[0]
+
+    assert module(loop) == "jit_loop" == "jit_" + PROGRAM_NAMES["decode_loop"]
+    assert module(chunk) == "jit_chunk_step" == \
+        "jit_" + PROGRAM_NAMES["chunk_step"]
+    assert module(write) == "jit_" + PROGRAM_NAMES["carry_write"]
+    assert module(scatter) == "jit_" + PROGRAM_NAMES["page_scatter"]
+
+
+def test_profiled_engine_outputs_match_untraced(tmp_path):
+    """Annotations under a running profiler leave the device math alone:
+    temperature-0 tokens are bitwise those of an untraced engine."""
+    global RNG
+    RNG = np.random.default_rng(21)
+    _, _, plain = _drive(trace=None)
+    RNG = np.random.default_rng(21)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, _, profiled = _drive(trace=TraceRecorder(profile=True))
+    finally:
+        jax.profiler.stop_trace()
+    for a, b in zip(plain, profiled):
+        np.testing.assert_array_equal(a, b)

@@ -38,7 +38,8 @@ NAME = "host-sync"
 #: completion, and its int(...) syncs are its contract.
 HOT_FUNCTIONS = frozenset({
     "step", "dispatch", "sync", "admit_handoffs",
-    "_prefill_chunk", "_prefill_phase", "_dispatch_decode_loop",
+    "_prefill_chunk", "_forward_chunk", "_prefill_phase",
+    "_dispatch_decode_loop",
     "_apply_decode_tokens", "_drain_ready",
 })
 
