@@ -34,6 +34,31 @@ def test_algorithmic_decoder_matches_table(spec):
     assert np.array_equal(dec2, tab)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=lambda d: np.dtype(d).name)
+@pytest.mark.parametrize("spec", [F.POSIT4, F.POSIT8, F.POSIT16],
+                         ids=lambda s: s.name)
+def test_posit_decode_bitwise(spec, dtype):
+    """Under jit, every code decodes bit for bit to its exact value
+    rounded once to ``dtype`` (zero and NaR to +0.0), as the codec's
+    table path gives it."""
+    n, es = spec.bits, spec.es
+    codes = jnp.arange(spec.ncodes, dtype=jnp.int32)
+    got = np.asarray(jax.jit(
+        lambda c: F.decode_posit_bits(c, n, es, dtype))(codes))
+    vals = F.code_values(spec)
+    exact = np.where(np.isfinite(vals), vals, 0.0).astype(dtype)
+    word = np.uint32 if dtype == jnp.float32 else np.uint16
+    assert got.dtype == np.dtype(dtype)
+    np.testing.assert_array_equal(got.view(word), exact.view(word))
+
+
+def test_posit_decode_refuses_formats_wider_than_float32():
+    """posit(32, 2) has 27-bit fractions: no float32 word holds them."""
+    with pytest.raises(ValueError, match="float32"):
+        F.decode_posit_bits(jnp.arange(4, dtype=jnp.int32), 32, 2)
+
+
 def test_posit_known_values():
     # posit(8,0): maxpos = 2^6; posit(16,1): maxpos = 2^28; posit(4,1): 16
     assert np.nanmax(F.code_values(F.POSIT8)) == 64.0
