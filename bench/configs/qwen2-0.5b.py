@@ -1,4 +1,5 @@
-"""qwen2-0.5b: weights from the seed, and the plain float32 reference.
+"""qwen2-0.5b: weights from the seed, the plain float32 reference, and
+its cost model (``costs.Dense``).
 
 Nothing here imports the program.  The weights are made here, in the
 parameter layout the program's ``ContinuousEngine`` takes; the harness
@@ -25,7 +26,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from bench.lib import numerics as nx
+from bench.lib import costs, numerics as nx
 
 QUERY_BLOCK = 256
 
@@ -89,6 +90,11 @@ def init_weights(key: jax.Array, c: Dict[str, Any]) -> Dict[str, Any]:
             v = jax.random.uniform(k, shape, jnp.float32, 0.8, 1.2)
         _set(out, path, v)
     return out
+
+
+def cost_model(c: Dict[str, Any]) -> costs.Dense:
+    """The yardstick's operations and bytes: a dense GQA transformer."""
+    return costs.Dense.from_config(c)
 
 
 def program_config(c: Dict[str, Any], base):

@@ -9,6 +9,25 @@ fp32, posit8 KV codes with bf16 scales), not what one implementation
 happens to move; operations count two per multiply-accumulate and
 only the work the algorithm needs: causal attention over the live
 context, logits only where a token is sampled.
+
+Each configuration brings its own cost model: the module beside its
+JSON defines ``cost_model(config)`` (``registry.cell`` refuses one that
+does not), and the harness hands what it returns to the per-layer
+readers as ``layers.Context.model``.  The shared readers
+(``bench/lib/layers.py``) call four methods on it, each returning a
+float:
+
+  decode_dispatch_flops(positions, steps)
+  decode_dispatch_bytes(positions, steps, page)
+      one K-step decode dispatch: row r starts at ``positions[r]`` and
+      decodes ``steps[r]`` tokens; ``page`` is the KV page's tokens
+  prefill_flops(start, real, sampled)
+  prefill_bytes(start, real)
+      one prefill chunk of ``real`` prompt tokens at ``start ..``;
+      ``sampled`` where it completes the prompt (its logits count)
+
+A configuration's own readers may call more methods on its own model.
+``Dense`` is the model of a dense GQA transformer.
 """
 
 from __future__ import annotations

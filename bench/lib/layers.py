@@ -20,7 +20,7 @@ __all__ = ["Context", "traced_decode_cost", "traced_prefill_cost",
 
 @dataclasses.dataclass
 class Context:
-    model: Any                     # costs.Dense
+    model: Any                     # the configuration's cost model
     peak: Dict[str, Any]           # the chip's published peaks
     engine: Dict[str, Any]         # the mix's engine settings
     window_s: float

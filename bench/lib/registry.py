@@ -6,7 +6,11 @@ adds a configuration, a mix or a metric with new files and new entries
 in ``BENCHMARK.json`` and edits nothing that is there:
 
   <config file>                   sizes, precision, program binding (JSON)
-  <config file minus .json>.py    its plain reference and weight init
+  <config file minus .json>.py    its plain reference, weight init and
+                                  ``cost_model(config)``: the yardstick's
+                                  count of the architecture's operations
+                                  and bytes (``bench/lib/costs.py`` says
+                                  what the shared readers call on it)
   bench/traffic/<traffic>.json    the mix's parameters and engine settings
   bench/metrics/<metric>.py       one per-layer metric's reader
 """
@@ -67,6 +71,7 @@ class Cell:
     config_name: str
     config: Dict[str, Any]        # the configuration file (sizes etc.)
     reference: Any                # module beside it: weights + reference
+    model: Any                    # its cost_model(config)
     traffic_name: str
     traffic: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]
@@ -79,8 +84,10 @@ def _applies(entry: Dict[str, Any], cell_name: str) -> bool:
 
 
 def cell(name: str, root: str = ROOT) -> Cell:
-    """Resolve workload ``name`` into its configuration, reference,
-    traffic and the metrics it reports."""
+    """Resolve workload ``name`` into its configuration, reference, cost
+    model, traffic and the metrics it reports.  A configuration module
+    without ``cost_model`` is refused: no architecture is counted as
+    another by default."""
     bench = load_benchmark(root)
     work = {w["name"]: w for w in bench["workloads"]}
     if name not in work:
@@ -94,6 +101,11 @@ def cell(name: str, root: str = ROOT) -> Cell:
         config = json.load(f)
     ref_path = os.path.splitext(cfile)[0] + ".py"
     reference = load_module(ref_path, "bench_ref_" + w["config"])
+    cost_model = getattr(reference, "cost_model", None)
+    if not callable(cost_model):
+        raise AttributeError(f"{ref_path} defines no cost_model(config): "
+                             "the yardstick has no count of its operations "
+                             "and bytes")
     with open(os.path.join(root, "bench", "traffic",
                            w["traffic"] + ".json")) as f:
         traffic = json.load(f)
@@ -107,6 +119,7 @@ def cell(name: str, root: str = ROOT) -> Cell:
             path, "bench_metric_" + m["name"].replace(".", "_"))))
     return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
                 config=config, reference=reference,
+                model=cost_model(config),
                 traffic_name=w["traffic"], traffic=traffic,
                 end_to_end=e2e, per_layer=readers,
                 run_seconds=int(bench["run_seconds"]))

@@ -4,8 +4,9 @@ moves ttft_p95_ms.
 The least time the chip could take for the traced prefill chunks,
 max(FLOPs / bf16 peak, bytes / HBM bandwidth) each, counting only the
 real prompt tokens a chunk forwards and logits only where the prompt
-completes (``costs.Dense``), over the device time of the chunk-step
-program's complete runs in the trace (jitted as ``chunk_step``)."""
+completes (the configuration's cost model), over the device time of
+the chunk-step program's complete runs in the trace (jitted as
+``chunk_step``)."""
 
 from bench.lib.layers import roofline_share, traced_prefill_cost
 

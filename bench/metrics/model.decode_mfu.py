@@ -1,11 +1,12 @@
 """model.decode_mfu -- layer: model step; unit %; source: device_trace;
 moves tpot_p95_ms.
 
-The decode FLOPs of the traced decode dispatches (``costs.Dense``:
-matmuls, causal attention over the live context, logits), over the
-device time of the decode-loop program's complete runs in the trace
-(the engine jits it as ``loop``) times the chip's bf16 peak: the decode
-step's share of the chip's peak while it runs."""
+The decode FLOPs of the traced decode dispatches, as the
+configuration's cost model counts them (for a dense model: matmuls,
+causal attention over the live context, logits), over the device time
+of the decode-loop program's complete runs in the trace (the engine
+jits it as ``loop``) times the chip's bf16 peak: the decode step's
+share of the chip's peak while it runs."""
 
 from bench.lib.layers import roofline_share, traced_decode_cost
 

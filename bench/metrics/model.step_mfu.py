@@ -2,9 +2,9 @@
 moves ttft_p95_ms.
 
 All model FLOPs of the traced steps (prefill chunks and decode
-dispatches, counted by ``costs.Dense``) over the traced window's
-seconds times the chip's bf16 peak: the whole step's share of the
-chip, which bounds what any one program's roofline can add."""
+dispatches, counted by the configuration's cost model) over the traced
+window's seconds times the chip's bf16 peak: the whole step's share of
+the chip, which bounds what any one program's roofline can add."""
 
 from bench.lib.layers import traced_decode_cost, window_prefill_flops
 

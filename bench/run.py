@@ -343,7 +343,6 @@ def end_to_end(recs: List[Rec], delivered: int, seconds: float,
 
 def layer_context(cell, cfg, engine, recs, steps, seconds, reduced, peak,
                   trace_growth):
-    from bench.lib.costs import Dense
     from bench.lib.layers import Context
     rec = engine.trace
     offset = -rec.events(WINDOW_EVENT)[0]["ts"]   # recorder -> window clock
@@ -356,7 +355,7 @@ def layer_context(cell, cfg, engine, recs, steps, seconds, reduced, peak,
         else:
             events.setdefault(e["kind"], []).append((t, e["rid"], e["args"]))
     prompts = {r.req.rid: len(r.prompt) for r in recs if r.req is not None}
-    return Context(model=Dense.from_config(cell.config), peak=peak,
+    return Context(model=cell.model, peak=peak,
                    engine=dict(cell.traffic["engine"]), window_s=seconds,
                    requests=recs, steps=steps, spans=spans, events=events,
                    prompt_len=prompts, device=reduced,

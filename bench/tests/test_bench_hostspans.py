@@ -143,12 +143,11 @@ def test_recorded_tpu_slice_with_engine_spans():
 
 
 def _ctx(spans, events, rids, device=None):
-    from bench.lib.costs import Dense
     from bench.lib.layers import Context
     c = find_cell("qwen2-0.5b.chat")
     recs = [types.SimpleNamespace(req=types.SimpleNamespace(rid=r))
             for r in rids]
-    return Context(model=Dense.from_config(c.config), peak=CPU_PEAK,
+    return Context(model=c.model, peak=CPU_PEAK,
                    engine=c.traffic["engine"], window_s=1.0, requests=recs,
                    steps=[], spans=spans, events=events, prompt_len={},
                    device=device, compiles_in_window=0)

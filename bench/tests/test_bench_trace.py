@@ -88,7 +88,6 @@ def test_decode_mfu_reads_the_loop_program_time():
     """Decode FLOPs over the decode-loop program's device time: below
     the loop's roofline share, and silent where the trace's runs and the
     traced dispatches disagree in number or there is no trace."""
-    from bench.lib.costs import Dense
     from bench.lib.layers import Context
     from bench.lib.registry import cell as find_cell
     from bench.tests.tiny import CPU_PEAK, run_module
@@ -98,7 +97,7 @@ def test_decode_mfu_reads_the_loop_program_time():
     steps = [Step(0.0, 0.1, [(600, 4), (900, 4)], traced=True),
              Step(0.1, 0.2, [], traced=True),
              Step(0.2, 0.3, [(700, 2)], traced=False)]
-    model = Dense.from_config(c.config)
+    model = c.model
 
     def ctx(device):
         return Context(model=model, peak=CPU_PEAK, engine=c.traffic["engine"],

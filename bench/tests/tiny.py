@@ -32,6 +32,7 @@ def tiny_cell(limit: float = 0.05, rate: float = 4.0):
                     num_attention_heads=4, num_key_value_heads=2,
                     head_dim=32, intermediate_size=256, vocab_size=512,
                     check={"token_gap_max": limit, "token_gap_mean": limit})
+    c.model = c.reference.cost_model(c.config)
     t = copy.deepcopy(c.traffic)
     t["engine"].update(max_batch=8, max_len=256, page_size=32, n_pages=64,
                        prefill_chunk_tokens=64)
